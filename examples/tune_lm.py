@@ -15,6 +15,7 @@ import sys
 sys.path.insert(0, "src")
 
 import repro.core as hpo
+from repro.launch.compile_cache import enable_compile_cache
 from repro.tune import LMTuneSpec, make_lm_objective
 
 
@@ -26,6 +27,7 @@ def main():
     ap.add_argument("--study", default="tune-lm")
     args = ap.parse_args()
 
+    enable_compile_cache()
     spec = LMTuneSpec(total_steps=args.steps, eval_every=max(args.steps // 8, 1))
     study = hpo.create_study(
         study_name=args.study,

@@ -10,6 +10,7 @@ joining late or dying early never corrupts the study.
 from __future__ import annotations
 
 import multiprocessing as mp
+import sys
 import time
 from typing import Callable
 
@@ -63,6 +64,23 @@ def worker_main(
     storage.close()
 
 
+def _held_accelerator() -> "str | None":
+    """Platform of a non-CPU JAX backend this process has initialised, or
+    None.  Never initialises a backend itself."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    # JAX has no public "is a backend initialised" query; this private one
+    # exists in the pinned jax==0.9.0, and tests/test_study.py fails loudly
+    # if an upgrade moves it
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return None
+    platform = jax.default_backend()
+    return None if platform == "cpu" else platform
+
+
 def run_workers(
     n_workers: int,
     storage_url: str,
@@ -97,7 +115,19 @@ def run_workers(
     sweeper: RUNNING trials whose worker stopped heartbeating for that many
     seconds are FAILed — or re-enqueued as WAITING with
     ``reclaim_requeue=True``, so a surviving worker's ``ask()`` re-runs them.
+
+    An accelerator belongs to one process: once this process holds one (a
+    non-CPU JAX backend is initialised), workers that reach for it would
+    fail or hang, so this raises ``RuntimeError`` instead of starting them.
     """
+    held = _held_accelerator()
+    if held is not None:
+        raise RuntimeError(
+            f"run_workers: this process already holds the {held} device, and "
+            "worker processes cannot share it. Run the study in this process "
+            "(Study.optimize or TrialSliceScheduler), or start the workers "
+            "before anything initialises JAX."
+        )
     server = None
     worker_url = storage_url
     if serve_storage:

@@ -154,16 +154,13 @@ def dominance_matrix(
     if n == 0:
         return np.zeros((0, 0), dtype=bool)
     if _resolve(engine, jit, n * V.shape[1]) == "jax":
-        try:
-            size = kops.pad_pow2_len(n)
-            if size != n:
-                P = np.full((size, V.shape[1]), np.inf)
-                P[:n] = V
-            else:
-                P = V
-            return np.asarray(_get_jax_dominance()(P))[:n, :n]
-        except Exception as e:  # device dispatch failed: downgrade loudly
-            _note_engine_fallback(f"dominance-device-error:{type(e).__name__}")
+        size = kops.pad_pow2_len(n)
+        if size != n:
+            P = np.full((size, V.shape[1]), np.inf)
+            P[:n] = V
+        else:
+            P = V
+        return np.asarray(_get_jax_dominance()(P))[:n, :n]
     out = np.empty((n, n), dtype=bool)
     m = V.shape[1]
     with np.errstate(invalid="ignore"):
@@ -542,18 +539,15 @@ class HypervolumeEstimator:
                     eng, len(pts) * len(samples), kops.DOM_JIT_THRESHOLD
                 )
         if eng != "numpy":
-            try:
-                n = len(pts)
-                if eng == "pallas":
-                    excl, total = kops.mc_hv_counts_op(pts, samples)
-                else:
-                    # pad point rows to pow2 with +inf (dominate nothing) so
-                    # XLA retraces O(log n) times; sample count is fixed
-                    P = kops.pad_pow2_rows(np.asarray(pts, dtype=np.float32), np.inf)
-                    excl, total = _get_jax_mc_counts()(P, samples.astype(np.float32))
-                return np.asarray(excl)[:n], float(total)
-            except Exception as e:  # device dispatch failed: downgrade loudly
-                _note_engine_fallback(f"mc-hv-device-error:{type(e).__name__}")
+            n = len(pts)
+            if eng == "pallas":
+                excl, total = kops.mc_hv_counts_op(pts, samples)
+            else:
+                # pad point rows to pow2 with +inf (dominate nothing) so
+                # XLA retraces O(log n) times; sample count is fixed
+                P = kops.pad_pow2_rows(np.asarray(pts, dtype=np.float32), np.inf)
+                excl, total = _get_jax_mc_counts()(P, samples.astype(np.float32))
+            return np.asarray(excl)[:n], float(total)
         return _mc_counts_numpy(pts, samples)
 
 

@@ -667,8 +667,9 @@ class TPESampler(BaseSampler):
         on numpy below it; ``"numpy"`` pins the pure-numpy path; ``"jax"`` /
         ``"pallas"`` force a device path regardless of size (falling back to
         numpy — logged once, counted in the ``sampler.engine_fallbacks``
-        telemetry counter — when jax is unavailable or the device call
-        fails).  ``jit_scoring=True`` is the historical spelling of
+        telemetry counter — only when jax is unavailable).  A device call
+        that fails raises: nothing downgrades a broken device path to the
+        host in silence.  ``jit_scoring=True`` is the historical spelling of
         ``engine="jax"``.
 
         ``multivariate=True`` switches batched ``Study.ask(n)`` waves to
@@ -849,22 +850,19 @@ class TPESampler(BaseSampler):
             # gemm_coeffs), so no group shape disables the device path.  The
             # matmul-bound form is already MXU-shaped, so "pallas" and "jax"
             # share this scorer.
-            try:
-                n = len(cands)
-                F = kops.pad_pow2_rows(l_est.gemm_features(cands), 0.0)
-                l_coeffs, l_const = l_est.gemm_coeffs()
-                g_coeffs, g_const = g_est.gemm_coeffs()
-                return np.asarray(
-                    _get_jax_gemm_score()(
-                        F,
-                        kops.pad_pow2_rows(l_coeffs, 0.0),
-                        kops.pad_pow2_vec(l_const, -np.inf),
-                        kops.pad_pow2_rows(g_coeffs, 0.0),
-                        kops.pad_pow2_vec(g_const, -np.inf),
-                    )
-                )[:n]
-            except Exception as e:  # device dispatch failed: downgrade loudly
-                self._note_engine_fallback(f"joint-device-error:{type(e).__name__}")
+            n = len(cands)
+            F = kops.pad_pow2_rows(l_est.gemm_features(cands), 0.0)
+            l_coeffs, l_const = l_est.gemm_coeffs()
+            g_coeffs, g_const = g_est.gemm_coeffs()
+            return np.asarray(
+                _get_jax_gemm_score()(
+                    F,
+                    kops.pad_pow2_rows(l_coeffs, 0.0),
+                    kops.pad_pow2_vec(l_const, -np.inf),
+                    kops.pad_pow2_rows(g_coeffs, 0.0),
+                    kops.pad_pow2_vec(g_const, -np.inf),
+                )
+            )[:n]
         return l_est.log_pdf(cands) - g_est.log_pdf(cands)
 
     def sample_joint(
@@ -1016,13 +1014,10 @@ class TPESampler(BaseSampler):
         work = len(cands) * (len(l_est.mus) + len(g_est.mus))
         eng = self._engine_for(work)
         if eng != "numpy":
-            try:
-                args = (cands, *_pad_est(l_est), *_pad_est(g_est))
-                if eng == "pallas":
-                    return np.asarray(kops.parzen_score_op(*args))
-                return np.asarray(_get_jax_score()(*args))
-            except Exception as e:  # device dispatch failed: downgrade loudly
-                self._note_engine_fallback(f"device-error:{type(e).__name__}")
+            args = (cands, *_pad_est(l_est), *_pad_est(g_est))
+            if eng == "pallas":
+                return np.asarray(kops.parzen_score_op(*args))
+            return np.asarray(_get_jax_score()(*args))
         return _score_numpy(
             cands,
             l_est.mus, l_est.sigmas, l_est._log_norm,

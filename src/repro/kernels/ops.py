@@ -14,11 +14,15 @@ observation count; the shared trace registry (:func:`bump_trace` /
 module does **not** import jax: the policy helpers are pure numpy, and the
 jitted wrappers below are materialized lazily via module ``__getattr__``.
 
-**Kernel wrappers (lazy, jax-importing).**  ``use_pallas``: on TPU hardware
-the kernels lower natively; on CPU we run ``interpret=True`` (Pallas executes
-the kernel body with the XLA interpreter — bit-accurate semantics, no
-Mosaic).  The model layers call the pure-jnp chunked implementations by
-default and switch to these when ``REPRO_USE_PALLAS=1`` (or on TPU backends).
+**Kernel wrappers (lazy, jax-importing).**  On a TPU backend the kernels
+lower natively through Mosaic, and nothing runs in interpret mode; on any
+other backend they run with ``interpret=True`` (Pallas executes the kernel
+body with the XLA interpreter — the same semantics, no Mosaic), which is how
+the CPU tests check them.  The sampler engine calls the Parzen and MC-HV
+kernels when :func:`pallas_enabled` (a TPU backend, or ``REPRO_USE_PALLAS=1``
+for the CPU tests).  No model layer calls the attention, SSD, sLSTM or
+cross-entropy kernels: the models use their pure-jnp chunked forms on every
+backend.
 """
 
 from __future__ import annotations

@@ -32,11 +32,10 @@ NEG_INF = -1e30
 
 
 def parzen_score_kernel(
-    c_ref,  # in: [bc] candidates
-    lmu_ref, lsig_ref, lln_ref,  # in: [bk] below-mixture components
-    gmu_ref, gsig_ref, gln_ref,  # in: [bk] above-mixture components
-    out_ref,  # out: [bc] log l - log g
-    lm_ref, ll_ref, gm_ref, gl_ref,  # scratch: [bc] online (m, l) per side
+    c_ref,  # in: [bc, 1] candidates, one per sublane row
+    comp_ref,  # in: [8, bk] component rows (see _COMP_ROWS), lane-dense
+    out_ref,  # out: [bc, 1] log l - log g
+    lm_ref, ll_ref, gm_ref, gl_ref,  # scratch: [bc, 1] online (m, l) per side
     *,
     n_comp_blocks: int,
 ):
@@ -51,26 +50,35 @@ def parzen_score_kernel(
 
     c = c_ref[...]
 
-    def accumulate(mu_ref, sig_ref, ln_ref, m_ref, l_ref):
-        z = (c[:, None] - mu_ref[...][None, :]) / sig_ref[...][None, :]
+    def accumulate(row, m_ref, l_ref):
+        mu = comp_ref[row:row + 1, :]
+        sig = comp_ref[row + 1:row + 2, :]
+        ln = comp_ref[row + 2:row + 3, :]
+        z = (c - mu) / sig  # [bc, bk]: candidates on sublanes, components on lanes
         # padding components carry log_norm = -inf; clamp to a finite
         # sentinel so the online max shift never mixes infinities
-        e = jnp.maximum(-0.5 * z * z + ln_ref[...][None, :], NEG_INF)
+        e = jnp.maximum(-0.5 * z * z + ln, NEG_INF)
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(e, axis=1))
+        m_new = jnp.maximum(m_prev, jnp.max(e, axis=1, keepdims=True))
         l_ref[...] = l_ref[...] * jnp.exp(m_prev - m_new) + jnp.sum(
-            jnp.exp(e - m_new[:, None]), axis=1
+            jnp.exp(e - m_new), axis=1, keepdims=True
         )
         m_ref[...] = m_new
 
-    accumulate(lmu_ref, lsig_ref, lln_ref, lm_ref, ll_ref)
-    accumulate(gmu_ref, gsig_ref, gln_ref, gm_ref, gl_ref)
+    accumulate(0, lm_ref, ll_ref)
+    accumulate(3, gm_ref, gl_ref)
 
     @pl.when(ik == n_comp_blocks - 1)
     def _finalize():
         log_l = lm_ref[...] + jnp.log(jnp.maximum(ll_ref[...], 1e-30))
         log_g = gm_ref[...] + jnp.log(jnp.maximum(gl_ref[...], 1e-30))
         out_ref[...] = log_l - log_g
+
+
+#: sublane rows of the packed component array: (mu, sigma, log_norm) of the
+#: below mixture, then of the above mixture, then two rows of padding that
+#: round the block up to one (8, 128) f32 tile height
+_COMP_ROWS = 8
 
 
 @functools.partial(
@@ -89,19 +97,28 @@ def _parzen_padded(
     C_p, K_p = cands.shape[0], l_mus.shape[0]
     nc, nk = C_p // block_c, K_p // block_k
 
+    # 2-D layouts only: 1-D blocks get a tiling from XLA that Mosaic refuses
+    # once a block is smaller than the array.  Components ride the lane axis
+    # (one packed DMA per step), candidates the sublane axis.
+    filler = jnp.ones_like(l_mus)
+    comps = jnp.stack(
+        [l_mus, l_sigmas, l_log_norm, g_mus, g_sigmas, g_log_norm, filler, filler]
+    )
     kernel = functools.partial(parzen_score_kernel, n_comp_blocks=nk)
-    comp_spec = pl.BlockSpec((block_k,), lambda ic, ik: (ik,))
-    cand_spec = pl.BlockSpec((block_c,), lambda ic, ik: (ic,))
+    cand_spec = pl.BlockSpec((block_c, 1), lambda ic, ik: (ic, 0))
     out = pl.pallas_call(
         kernel,
         grid=(nc, nk),
-        in_specs=[cand_spec] + [comp_spec] * 6,
+        in_specs=[
+            cand_spec,
+            pl.BlockSpec((_COMP_ROWS, block_k), lambda ic, ik: (0, ik)),
+        ],
         out_specs=cand_spec,
-        out_shape=jax.ShapeDtypeStruct((C_p,), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_c,), jnp.float32) for _ in range(4)],
+        out_shape=jax.ShapeDtypeStruct((C_p, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_c, 1), jnp.float32) for _ in range(4)],
         interpret=interpret,
-    )(cands, l_mus, l_sigmas, l_log_norm, g_mus, g_sigmas, g_log_norm)
-    return out
+    )(cands.reshape(C_p, 1), comps)
+    return out[:, 0]
 
 
 def parzen_score(

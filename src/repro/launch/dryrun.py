@@ -42,7 +42,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str, verbose: bool
     mesh_name = "pod2x16x16" if multi_pod else "pod16x16"
     t0 = time.time()
     cell = build_step(cfg, shape, mesh)
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(
             cell.step, in_shardings=cell.in_shardings, donate_argnums=cell.donate
         )

@@ -8,9 +8,9 @@ keep seeing 1 device).
 from __future__ import annotations
 
 import jax
-import numpy as np
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_host_mesh", "slice_mesh"]
+__all__ = ["make_production_mesh", "make_host_mesh", "make_auto_mesh", "slice_mesh"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,12 +19,21 @@ def make_production_mesh(*, multi_pod: bool = False):
     axis crossing the DCN/inter-pod links."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_host_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh over host (CPU) devices for tests."""
-    return jax.make_mesh(shape, axes)
+    return make_auto_mesh(shape, axes)
+
+
+def make_auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules and
+    ``with_sharding_constraint`` anchors in ``models/`` speak GSPMD, which
+    the ``Explicit`` axes that ``jax.make_mesh`` now defaults to refuse."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes), devices=devices
+    )
 
 
 def slice_mesh(mesh, n_slices: int, axis: str = "data"):
@@ -43,5 +52,5 @@ def slice_mesh(mesh, n_slices: int, axis: str = "data"):
     for i in range(n_slices):
         sl = [slice(None)] * devs.ndim
         sl[ax] = slice(i * chunk, (i + 1) * chunk)
-        out.append(Mesh(devs[tuple(sl)], mesh.axis_names))
+        out.append(Mesh(devs[tuple(sl)], mesh.axis_names, axis_types=mesh.axis_types))
     return out

@@ -33,7 +33,7 @@ def measure(arch: str, shape: str, overrides: dict, multi_pod: bool = False) -> 
     mesh = make_production_mesh(multi_pod=multi_pod)
     cell = build_step(cfg, shape, mesh)
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jax.jit(
             cell.step, in_shardings=cell.in_shardings, donate_argnums=cell.donate
         ).lower(*cell.args).compile()

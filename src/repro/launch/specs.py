@@ -30,15 +30,15 @@ from repro.models.sharding import (
 )
 from repro.serve import make_decode_step, make_prefill_step
 from repro.train.optimizer import Optimizer
-from repro.train.train_loop import TrainConfig, make_optimizer_for, make_train_step, _opt_shardings
+from repro.train.train_loop import (
+    TrainConfig,
+    _opt_shardings,
+    batch_shardings,
+    make_optimizer_for,
+    make_train_step,
+)
 
 __all__ = ["input_specs", "build_step", "Cell"]
-
-_BATCH_LOGICAL = {
-    "tokens": ("batch", "seq"),
-    "labels": ("batch", "seq"),
-    "image_embeds": ("batch", "seq", "embed"),
-}
 
 
 def _batch_abstract(cfg: ModelConfig, batch: int, seq: int) -> dict:
@@ -60,19 +60,6 @@ def _batch_abstract(cfg: ModelConfig, batch: int, seq: int) -> dict:
         "tokens": jax.ShapeDtypeStruct((batch, seq), i32),
         "labels": jax.ShapeDtypeStruct((batch, seq), i32),
     }
-
-
-def _batch_shardings(batch_abs: dict, mesh, rules: ShardingRules):
-    def one(name, s):
-        if name == "image_embeds":
-            logical = ("batch", None, None)
-        elif len(s.shape) == 3:  # audio [B, K, S]
-            logical = ("batch", None, "seq")
-        else:
-            logical = ("batch", "seq")
-        return logical_to_sharding(logical, s.shape, mesh, rules)
-
-    return {k: one(k, v) for k, v in batch_abs.items()}
 
 
 def input_specs(cfg: ModelConfig, shape_name: str) -> dict:
@@ -116,7 +103,7 @@ def build_step(cfg: ModelConfig, shape_name: str, mesh, tcfg: TrainConfig | None
         opt_abs = jax.eval_shape(opt.init, aps)
         o_sh = _opt_shardings(opt_abs, p_sh)
         batch_abs = input_specs(cfg, shape_name)
-        b_sh = _batch_shardings(batch_abs, mesh, rules)
+        b_sh = batch_shardings(batch_abs, mesh, rules)
         step = wrap_with_sharding_ctx(
             make_train_step(cfg, opt, cfg.train_microbatch), mesh, rules
         )
@@ -145,7 +132,7 @@ def build_step(cfg: ModelConfig, shape_name: str, mesh, tcfg: TrainConfig | None
 
     if shp.kind == "prefill":
         batch_abs = input_specs(cfg, shape_name)
-        b_sh = _batch_shardings(batch_abs, mesh, rules)
+        b_sh = batch_shardings(batch_abs, mesh, rules)
         step = wrap_with_sharding_ctx(make_prefill_step(cfg), mesh, rules)
         return Cell(
             name=f"{cfg.name}:{shape_name}",

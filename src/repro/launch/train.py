@@ -28,9 +28,11 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro import configs
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import count_params
     from repro.train import TrainConfig, Trainer, make_data
 
+    enable_compile_cache()
     cfg = configs.get_smoke_config(args.arch) if args.smoke else configs.get_config(args.arch)
     print(f"[train] {cfg.name}: {count_params(cfg)/1e6:.1f}M params")
     tcfg = TrainConfig(

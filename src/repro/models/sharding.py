@@ -7,6 +7,7 @@ falls back to replication (e.g. kv_heads=4 on a 16-way "model" axis).
 from __future__ import annotations
 
 import dataclasses
+import threading
 from typing import Any, Sequence
 
 import jax
@@ -159,9 +160,17 @@ def with_logical_constraint(x, logical: Sequence[str | None], mesh: Mesh | None,
 # shardings (batch over ("pod","data"), experts over "model", ...).  Without
 # these anchors GSPMD can propagate a *replicated* batch through the layer
 # scan — catastrophic for memory (verified on the smollm dry-run: 409 GiB/dev
-# before anchors, ~1 GiB after).
+# before anchors, ~1 GiB after).  The context is per thread: concurrent
+# trials trace their steps on their own slices' meshes at the same time.
 
-_ACTIVE: list = []
+_LOCAL = threading.local()
+
+
+def _active() -> list:
+    stack = getattr(_LOCAL, "stack", None)
+    if stack is None:
+        stack = _LOCAL.stack = []
+    return stack
 
 
 class activation_sharding:
@@ -169,19 +178,20 @@ class activation_sharding:
         self.pair = (mesh, rules)
 
     def __enter__(self):
-        _ACTIVE.append(self.pair)
+        _active().append(self.pair)
         return self
 
     def __exit__(self, *exc):
-        _ACTIVE.pop()
+        _active().pop()
         return False
 
 
 def constrain(x, logical: Sequence[str | None]):
     """Sharding anchor using the ambient (mesh, rules); identity when absent."""
-    if not _ACTIVE:
+    stack = _active()
+    if not stack:
         return x
-    mesh, rules = _ACTIVE[-1]
+    mesh, rules = stack[-1]
     return with_logical_constraint(x, logical, mesh, rules)
 
 

@@ -22,7 +22,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 __all__ = ["int8_compress", "int8_decompress", "topk_mask", "compressed_psum", "wrap_grad_fn"]
 
@@ -82,12 +81,12 @@ def wrap_grad_fn(grad_fn: Callable, mesh, axis_name: str = "data",
                 jax.tree.map(lambda o: o[1], out, is_leaf=is_pair),
             )
 
-        return shard_map(
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(), P(axis_name), P()),
             out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )(params, batch, residual)
 
     return reduced

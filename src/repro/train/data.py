@@ -4,14 +4,15 @@ Synthetic streams are *stateless*: batch at step ``s`` is a pure function of
 (seed, s), so resuming from a checkpoint just means ``skip_to(step)`` — no
 iterator state to persist, and every data-parallel worker can slice its shard
 of the global batch independently (deterministic data skip on restart).
+
+Batches are host numpy arrays: the trainer copies each one straight to the
+devices of its own mesh, never through the default device.
 """
 
 from __future__ import annotations
 
 import dataclasses
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.models import ModelConfig
@@ -64,8 +65,8 @@ class SyntheticLM:
                 toks[:, 1::2] = (toks[:, 0::2][:, : toks[:, 1::2].shape[1]] + 1) % cfg.vocab
         if cfg.modality == "audio":
             return {
-                "tokens": jnp.asarray(toks[:, :, :-1]),
-                "labels": jnp.asarray(toks[:, :, 1:]),
+                "tokens": np.ascontiguousarray(toks[:, :, :-1]),
+                "labels": np.ascontiguousarray(toks[:, :, 1:]),
             }
         if cfg.modality == "vlm":
             rng = np.random.RandomState((self.seed, step, 7) .__hash__() % (2**31))
@@ -74,11 +75,14 @@ class SyntheticLM:
                 [np.zeros((self.batch, cfg.img_tokens), np.int32), toks[:, 1:]], axis=1
             )
             return {
-                "tokens": jnp.asarray(toks[:, :-1]),
-                "image_embeds": jnp.asarray(img),
-                "labels": jnp.asarray(labels),
+                "tokens": np.ascontiguousarray(toks[:, :-1]),
+                "image_embeds": img,
+                "labels": labels,
             }
-        return {"tokens": jnp.asarray(toks[:, :-1]), "labels": jnp.asarray(toks[:, 1:])}
+        return {
+            "tokens": np.ascontiguousarray(toks[:, :-1]),
+            "labels": np.ascontiguousarray(toks[:, 1:]),
+        }
 
     def next_batch(self) -> dict:
         b = self.batch_at(self._step)
@@ -113,7 +117,10 @@ class MemmapTokens:
             self.batch, self.seq + 1
         )
         self._step += 1
-        return {"tokens": jnp.asarray(chunk[:, :-1]), "labels": jnp.asarray(chunk[:, 1:])}
+        return {
+            "tokens": np.ascontiguousarray(chunk[:, :-1]),
+            "labels": np.ascontiguousarray(chunk[:, 1:]),
+        }
 
 
 def make_data(cfg: ModelConfig, batch: int, seq: int, seed: int = 0, path: str | None = None):

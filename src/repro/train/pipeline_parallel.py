@@ -25,7 +25,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 __all__ = ["pipelined_apply", "make_pp_train_step"]
 
@@ -86,12 +85,12 @@ def pipelined_apply(
         outputs = jax.lax.psum(outputs, stage_axis)
         return outputs
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(stage_axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(params, x)
 
 

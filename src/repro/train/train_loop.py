@@ -4,10 +4,11 @@
 trainer and the dry-run: grad of the chunked-CE loss, optional microbatch
 accumulation (scan), optimizer update, donation-friendly signature.
 
-``Trainer`` adds the production concerns: sharded init, checkpoint/restart
-(auto-resume from the latest step), deterministic data skip on resume, eval
-hooks that feed the HPO pruner, and graceful preemption (SIGTERM -> final
-checkpoint).
+``Trainer`` adds the production concerns: sharded init and a step pinned to
+the trainer's mesh (a trial on mesh slice k lives on slice k's devices),
+checkpoint/restart (auto-resume from the latest step), deterministic data
+skip on resume, eval hooks that feed the HPO pruner, and graceful preemption
+(SIGTERM -> final checkpoint).
 """
 
 from __future__ import annotations
@@ -21,6 +22,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models import (
     ModelConfig,
@@ -29,12 +31,20 @@ from repro.models import (
     loss_fn,
     params_logical,
 )
-from repro.models.sharding import ShardingRules, logical_to_sharding, tree_shardings
+from repro.models.sharding import (
+    TRAIN_RULES,
+    ShardingRules,
+    logical_to_sharding,
+    tree_shardings,
+    wrap_with_sharding_ctx,
+)
 
 from .checkpoint import CheckpointManager
 from .optimizer import Optimizer, make_optimizer, warmup_cosine
 
-__all__ = ["TrainConfig", "make_train_step", "Trainer", "make_sharded_init"]
+__all__ = [
+    "TrainConfig", "make_train_step", "Trainer", "make_sharded_init", "batch_shardings",
+]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -120,6 +130,22 @@ def make_sharded_init(cfg: ModelConfig, opt: Optimizer, mesh, rules: ShardingRul
     return jax.jit(init, out_shardings=(p_sh, o_sh)), p_sh, o_sh
 
 
+def batch_shardings(batch: dict, mesh, rules: ShardingRules) -> dict:
+    """Shardings for a batch dict (arrays or ShapeDtypeStructs): the batch
+    dim over the rules' batch axes, sequence over "seq"."""
+
+    def one(name, s):
+        if name == "image_embeds":
+            logical = ("batch", None, None)
+        elif len(s.shape) == 3:  # audio [B, K, S]
+            logical = ("batch", None, "seq")
+        else:
+            logical = ("batch", "seq")
+        return logical_to_sharding(logical, s.shape, mesh, rules)
+
+    return {k: one(k, v) for k, v in batch.items()}
+
+
 def _opt_shardings(opt_abs, param_shardings):
     """Optimizer state shardings: inherit from the matching parameter where
     shapes coincide (adam m/v); adafactor's factored vr/vc inherit the param
@@ -166,8 +192,28 @@ def _opt_shardings(opt_abs, param_shardings):
     return jax.tree.unflatten(jax.tree.structure(opt_abs), vals)
 
 
+def _jit_on_mesh(train_step, mesh, rules: ShardingRules, state_sh, b_sh):
+    """``train_step`` jitted with its state and batch pinned to ``mesh``."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    p_sh, o_sh = state_sh
+    scalar_sh = NamedSharding(mesh, PartitionSpec())
+    return jax.jit(
+        wrap_with_sharding_ctx(train_step, mesh, rules),
+        in_shardings=(p_sh, o_sh, scalar_sh, b_sh),
+        out_shardings=(p_sh, o_sh, scalar_sh),
+        donate_argnums=(0, 1),
+    )
+
+
 class Trainer:
-    """Host-side loop with checkpoint/restart and pruner hooks."""
+    """Host-side loop with checkpoint/restart and pruner hooks.
+
+    With a ``mesh``, parameters and optimizer state are born on that mesh
+    (``make_sharded_init`` under ``rules``, default ``TRAIN_RULES``) and the
+    step is jitted with the matching in/out shardings, so every array of the
+    run stays on the mesh's devices.  Without one, arrays live on the
+    default device."""
 
     def __init__(
         self,
@@ -203,24 +249,37 @@ class Trainer:
         self._install_sigterm()
         cfg, tcfg = self.cfg, self.tcfg
         key = jax.random.PRNGKey(tcfg.seed)
-        params = init_model_params(cfg, key)
-        opt_state = self.opt.init(params)
+        train_step = make_train_step(cfg, self.opt, tcfg.microbatch)
         start_step = 0
+        state_sh = b_sh = None
+        if self.mesh is None:
+            params = init_model_params(cfg, key)
+            opt_state = self.opt.init(params)
+            step_fn = jax.jit(train_step, donate_argnums=(0, 1))
+        else:
+            mesh, rules = self.mesh, self.rules or TRAIN_RULES
+            init, p_sh, o_sh = make_sharded_init(cfg, self.opt, mesh, rules)
+            params, opt_state = init(key)
+            state_sh = (p_sh, o_sh)
+            step_fn = None  # jitted on the first batch, whose shapes it needs
         if self.ckpt is not None:
-            restored = self.ckpt.restore_latest((params, opt_state))
+            restored = self.ckpt.restore_latest((params, opt_state), state_sh)
             if restored is not None:
                 start_step, (params, opt_state) = restored
-        step_fn = jax.jit(
-            make_train_step(cfg, self.opt, tcfg.microbatch), donate_argnums=(0, 1)
-        )
 
         self.data.skip_to(start_step)
         losses = []
         last = None
         for step in range(start_step, tcfg.total_steps):
             batch = self.data.next_batch()
+            if self.mesh is not None:
+                if step_fn is None:
+                    b_sh = batch_shardings(batch, mesh, rules)
+                    step_fn = _jit_on_mesh(train_step, mesh, rules, state_sh, b_sh)
+                # host numpy straight onto this mesh's devices
+                batch = jax.device_put(batch, b_sh)
             params, opt_state, metrics = step_fn(
-                params, opt_state, jnp.asarray(step, jnp.int32), batch
+                params, opt_state, np.int32(step), batch
             )
             last = metrics
             if (step + 1) % tcfg.eval_every == 0 or step + 1 == tcfg.total_steps:
@@ -229,7 +288,8 @@ class Trainer:
                 if self.report_fn is not None and self.report_fn(step + 1, loss):
                     # pruned by the HPO layer: stop immediately, do not checkpoint
                     # (the paper's no-repechage design: pruned trials never resume)
-                    return {"pruned": True, "last_loss": loss, "step": step + 1}
+                    return {"pruned": True, "last_loss": loss, "step": step + 1,
+                            "params": params}
             if self.ckpt is not None and (
                 (step + 1) % tcfg.checkpoint_every == 0 or self._preempted
             ):

@@ -12,6 +12,7 @@ slices come from ``launch.mesh.slice_mesh(make_production_mesh(), K)``.
 
 from __future__ import annotations
 
+import logging
 import threading
 from typing import Callable
 
@@ -20,6 +21,8 @@ from repro.core import telemetry
 from repro.core.frozen import TrialState
 
 __all__ = ["TrialSliceScheduler"]
+
+_log = logging.getLogger(__name__)
 
 
 class TrialSliceScheduler:
@@ -119,7 +122,11 @@ class TrialSliceScheduler:
                     self.study.tell(trial, final, state=TrialState.PRUNED)
                     self._log("pruned", slice_id, trial.number)
                     continue
-                except Exception:
+                except Exception as e:
+                    # a failed trial frees its slice for the next one; the
+                    # traceback goes to the log, the cause onto the trial
+                    _log.exception("trial %d failed on slice %d", trial.number, slice_id)
+                    trial.set_system_attr("fail:exception", repr(e))
                     self.study.tell(trial, state=TrialState.FAIL)
                     self._log("failed", slice_id, trial.number)
                     continue

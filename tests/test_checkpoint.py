@@ -95,7 +95,8 @@ from repro.models.sharding import TRAIN_RULES, tree_shardings
 from repro.train import restore_pytree
 
 cfg = configs.get_smoke_config("tinyllama-1.1b")
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_auto_mesh
+mesh = make_auto_mesh((2, 2), ("data", "model"))
 aps = abstract_params(cfg)
 sh = tree_shardings(aps, params_logical(cfg), mesh, TRAIN_RULES)
 step, params = restore_pytree({path!r}, aps, sh)

@@ -315,6 +315,37 @@ class TestEngineFallback:
         finally:
             telemetry.disable()
 
+    def test_tpe_device_error_raises(self, monkeypatch):
+        """A failing device call surfaces; it never downgrades to numpy."""
+        import repro.core.samplers.tpe as tpe_mod
+
+        def broken(*args):
+            raise RuntimeError("device call failed")
+
+        monkeypatch.setattr(tpe_mod, "_get_jax_score", lambda: broken)
+        telemetry.enable()
+        try:
+            telemetry.reset()
+            sampler = hpo.TPESampler(seed=0, engine="jax", n_startup_trials=3)
+            study = hpo.create_study(sampler=sampler)
+            study.optimize(lambda t: t.suggest_float("x", -3, 3) ** 2, n_trials=3)
+            trial = study.ask()
+            with pytest.raises(RuntimeError, match="device call failed"):
+                trial.suggest_float("x", -3, 3)
+            assert telemetry.counter("sampler.engine_fallbacks").value == 0
+        finally:
+            telemetry.disable()
+
+    def test_hypervolume_device_error_raises(self, monkeypatch):
+        def broken(*args):
+            raise RuntimeError("device call failed")
+
+        monkeypatch.setattr(moo, "_get_jax_mc_counts", lambda: broken)
+        est = moo.HypervolumeEstimator(method="mc", engine="jax")
+        pts = np.random.RandomState(0).rand(6, 5)
+        with pytest.raises(RuntimeError, match="device call failed"):
+            est.hypervolume(pts, np.full(5, 1.1))
+
     def test_mixed_categorical_groups_keep_device_path(self):
         """Regression: categorical dims used to silently disable the joint
         device scorer; the gemm one-hot encoding keeps it on with zero

@@ -46,7 +46,7 @@ opt = make_optimizer_for(cfg, TrainConfig())
 opt_state = opt.init(params)
 data = SyntheticLM(cfg, batch=8, seq=32, seed=0)
 batch = data.next_batch()
-with mesh:
+with jax.set_mesh(mesh):
     jitted = jax.jit(cell.step)
     p, o, m = jitted(params, opt_state, jnp.int32(0), batch)
     loss1 = float(m["loss"])
@@ -65,7 +65,8 @@ def test_pipeline_parallel_matches_sequential():
         """
 import jax, jax.numpy as jnp, numpy as np
 from repro.train.pipeline_parallel import pipelined_apply
-mesh = jax.make_mesh((4,), ("stage",))
+from repro.launch.mesh import make_auto_mesh
+mesh = make_auto_mesh((4,), ("stage",))
 S, M, mb, d = 4, 8, 2, 16
 key = jax.random.PRNGKey(0)
 params = jax.random.normal(key, (S, d, d)) * 0.3
@@ -98,16 +99,16 @@ def test_gradient_compression_psum():
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.train.compression import compressed_psum, int8_compress, int8_decompress
 
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_auto_mesh
+mesh = make_auto_mesh((8,), ("data",))
 x = jnp.arange(64.0).reshape(8, 8) / 64.0
 
 def body(xs):
     return compressed_psum(xs[0], "data", codec="int8")
 
-out = shard_map(body, mesh=mesh, in_specs=(P("data"),), out_specs=P(), check_rep=False)(x)
+out = jax.shard_map(body, mesh=mesh, in_specs=(P("data"),), out_specs=P(), check_vma=False)(x)
 expect = x.sum(axis=0)
 err = float(jnp.abs(out - expect).max()) / float(jnp.abs(expect).max())
 assert err < 0.05, err  # int8 quantization error bound
@@ -160,6 +161,52 @@ print("SCHED_OK", len(done), len(pruned))
 """
     )
     assert "SCHED_OK" in out
+
+
+def test_trainer_with_mesh_keeps_its_arrays_on_the_mesh():
+    out = run_sub(
+        """
+import jax, numpy as np
+from repro import configs
+from repro.launch.mesh import make_auto_mesh
+from repro.train import SyntheticLM, TrainConfig, Trainer
+
+cfg = configs.get_smoke_config("smollm-135m")
+tcfg = TrainConfig(total_steps=4, eval_every=2, warmup_steps=1)
+dev = jax.devices()[1]  # not the default device
+mesh = make_auto_mesh((1, 1), ("data", "model"), devices=[dev])
+res = Trainer(cfg, tcfg, SyntheticLM(cfg, 2, 32), mesh=mesh).run()
+placed = {d for x in jax.tree.leaves(res["params"]) for d in x.devices()}
+assert placed == {dev}, placed
+ref = Trainer(cfg, tcfg, SyntheticLM(cfg, 2, 32)).run()
+np.testing.assert_allclose(res["losses"], ref["losses"], rtol=1e-4)
+print("MESH_TRAINER_OK")
+""",
+        n_devices=2,
+    )
+    assert "MESH_TRAINER_OK" in out
+
+
+def test_chip_smoke_four_chip_phase_on_virtual_devices():
+    """``chip_smoke.py --four-chips``'s phase: four concurrent trials, one
+    per device, whose losses equal the same trainings run one after another
+    on the first device."""
+    root = os.path.dirname(SRC)
+    out = run_sub(
+        f"""
+sys.path.insert(0, {root!r})
+import chip_smoke
+from repro import configs
+
+cfg = configs.get_smoke_config("smollm-135m")
+res = chip_smoke.phase_four_chips(0, cfg, "cpu", batch=2, seq=32, steps=4)
+assert len({{tuple(r["devices"]) for r in res["trials"].values()}}) == 4
+assert res["overlap_s"] > 0 and res["loss_max_abs_diff"] <= 1e-4
+print("FOUR_SLICES_OK", res["loss_max_abs_diff"])
+""",
+        n_devices=4,
+    )
+    assert "FOUR_SLICES_OK" in out
 
 
 def test_dryrun_single_cell_multi_pod():

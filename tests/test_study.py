@@ -140,6 +140,29 @@ def test_distributed_processes_sqlite(tmp_path):
     assert s.best_value < 10.0
 
 
+def test_held_accelerator_check_finds_its_private_jax_query():
+    # run_workers reads this private symbol in any process that imported jax;
+    # an upgrade that moves it must fail here, not in every run_workers call
+    pytest.importorskip("jax")
+    from jax._src import xla_bridge
+
+    assert callable(xla_bridge.backends_are_initialized)
+
+
+def test_run_workers_refuses_while_this_process_holds_an_accelerator(tmp_path, monkeypatch):
+    jax = pytest.importorskip("jax")
+    from repro.core import distributed
+
+    jax.devices()  # initialise this process's backend (the CPU here)
+    assert distributed._held_accelerator() is None
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    url = f"sqlite:///{tmp_path}/held.db"
+    hpo.create_study(study_name="held", storage=url)
+    with pytest.raises(RuntimeError, match="holds the tpu device"):
+        hpo.run_workers(2, url, "held", _sphere, n_trials_per_worker=1)
+    assert len(hpo.load_study("held", url).trials) == 0  # no worker started
+
+
 def test_distributed_processes_journal(tmp_path):
     url = f"journal://{tmp_path}/dist.journal"
     hpo.create_study(study_name="dist", storage=url)
