@@ -1015,9 +1015,10 @@ class TPESampler(BaseSampler):
         eng = self._engine_for(work)
         if eng != "numpy":
             args = (cands, *_pad_est(l_est), *_pad_est(g_est))
-            if eng == "pallas":
-                return np.asarray(kops.parzen_score_op(*args))
-            return np.asarray(_get_jax_score()(*args))
+            score = kops.parzen_score_op if eng == "pallas" else _get_jax_score()
+            out = score(*args)
+            with telemetry.span("tpe.score.fetch"):  # wait, then device to host
+                return np.asarray(out)
         return _score_numpy(
             cands,
             l_est.mus, l_est.sigmas, l_est._log_norm,
@@ -1038,14 +1039,15 @@ class TPESampler(BaseSampler):
         key = (param_name, low, high)
         ests = cache.get(key)
         if ests is None:
-            l_est = _ParzenEstimator(
-                below, low, high, w_below,
-                self._consider_prior, self._prior_weight, self._magic_clip,
-            )
-            g_est = _ParzenEstimator(
-                above, low, high, w_above,
-                self._consider_prior, self._prior_weight, self._magic_clip,
-            )
+            with telemetry.span("tpe.estimate"):
+                l_est = _ParzenEstimator(
+                    below, low, high, w_below,
+                    self._consider_prior, self._prior_weight, self._magic_clip,
+                )
+                g_est = _ParzenEstimator(
+                    above, low, high, w_above,
+                    self._consider_prior, self._prior_weight, self._magic_clip,
+                )
             cache[key] = ests = (l_est, g_est)
         l_est, g_est = ests
         cands = l_est.sample(self._rng, self._n_ei)
@@ -1115,10 +1117,9 @@ class TPESampler(BaseSampler):
                 np.add.at(counts, idxs.astype(int), ws)
                 return counts / counts.sum()
 
-            cache[key] = probs = (
-                weighted_probs(below, w_below),
-                weighted_probs(above, w_above),
-            )
+            with telemetry.span("tpe.estimate"):
+                probs = (weighted_probs(below, w_below), weighted_probs(above, w_above))
+            cache[key] = probs
         p_l, p_g = probs
         cands = self._rng.choice(k, size=self._n_ei, p=p_l)
         score = np.log(p_l[cands] + EPS) - np.log(p_g[cands] + EPS)

@@ -343,7 +343,6 @@ class Study:
         if self._joint_miss_logged:
             return
         self._joint_miss_logged = True
-        telemetry.inc("study.joint_miss")
         # the per-study flag above already dedupes; a global log_once keyed
         # on id(self) would go silent when a dead study's id gets reused
         _log.log(

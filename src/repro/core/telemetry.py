@@ -24,6 +24,14 @@ Metric names are dotted lowercase ``component.operation[.detail]`` —
 e.g. ``study.ask`` (histogram, seconds), ``client.rpc.get_trial`` (histogram),
 ``cached.get_trial.hit`` (counter), ``server.bytes_in`` (counter).  Latency
 histograms are always in **seconds**.
+
+An enabled span is also a ``jax.profiler.TraceAnnotation`` of the same name
+in a process that has imported jax, so under a profiler trace it sits on its
+thread's host line, on the device's clock, nested in the span that encloses
+it.  ``span(name, trial=3)`` attaches ids to the annotation (not to the
+name); spans nested in it on the same thread inherit them, so a trainer run
+by the trial scheduler labels its spans with the trial.  This module never
+imports jax itself (the storage server uses it without jax).
 """
 
 from __future__ import annotations
@@ -32,6 +40,7 @@ import bisect
 import math
 import os
 import socket
+import sys
 import threading
 import time
 from typing import Any, Iterator
@@ -209,18 +218,48 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
-class _Span:
-    __slots__ = ("_hist", "_t0")
+_TraceAnnotation: Any = None
+#: the ids of the innermost span open on each thread
+_span_ids = threading.local()
 
-    def __init__(self, hist: Histogram):
+
+def _trace_annotation() -> Any:
+    """``jax.profiler.TraceAnnotation``, resolved once jax has been imported
+    by the program; ``None`` before that (no profiler can run without jax,
+    and a process that never imports it never pays for the import)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None and "jax" in sys.modules:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            return None
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation
+
+
+class _Span:
+    __slots__ = ("_hist", "_ids", "_t0", "_ann", "_outer")
+
+    def __init__(self, hist: Histogram, ids: dict):
         self._hist = hist
+        self._ids = ids
 
     def __enter__(self) -> "_Span":
+        self._outer = outer = getattr(_span_ids, "ids", None) or {}
+        ids = {**outer, **self._ids} if self._ids else outer
+        _span_ids.ids = ids
+        annotation = _trace_annotation()
+        self._ann = None if annotation is None else annotation(self._hist.name, **ids)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc: Any) -> None:
         self._hist.observe(time.perf_counter() - self._t0)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _span_ids.ids = self._outer
 
 
 class MetricsRegistry:
@@ -268,10 +307,10 @@ class MetricsRegistry:
         if self.enabled:
             self.histogram(name).observe(seconds)
 
-    def span(self, name: str) -> Any:
+    def span(self, name: str, **ids: Any) -> Any:
         if not self.enabled:
             return _NOOP
-        return _Span(self.histogram(name))
+        return _Span(self.histogram(name), ids)
 
     def snapshot(self) -> dict[str, Any]:
         """JSON-safe dump: counters/gauges as scalars, histograms summarized."""
@@ -338,10 +377,13 @@ def observe(name: str, seconds: float) -> None:
         _registry.histogram(name).observe(seconds)
 
 
-def span(name: str) -> Any:
+def span(name: str, **ids: Any) -> Any:
+    """Time a block into the histogram ``name`` and, where jax is loaded,
+    annotate it on the profiler's host timeline with ``ids`` (such as
+    ``trial=<number>``); the shared ``_NOOP`` while the registry is off."""
     if not _registry.enabled:
         return _NOOP
-    return _Span(_registry.histogram(name))
+    return _Span(_registry.histogram(name), ids)
 
 
 def snapshot() -> dict[str, Any]:

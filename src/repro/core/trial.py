@@ -18,6 +18,7 @@ import datetime
 import math
 from typing import TYPE_CHECKING, Any, Sequence
 
+from . import telemetry
 from .distributions import (
     BaseDistribution,
     CategoricalDistribution,
@@ -146,17 +147,17 @@ class Trial(BaseTrial):
             check_distribution_compatibility(frozen.distributions[name], distribution)
             return frozen.params[name]
 
-        if distribution.single():
-            # domain of size one: no sampling needed
-            internal = distribution.to_internal_repr(
-                distribution.to_external_repr(
-                    distribution.low if hasattr(distribution, "low") else 0.0
+        with telemetry.span("trial.suggest", trial=frozen.number):
+            if distribution.single():
+                # domain of size one: no sampling needed
+                internal = distribution.to_internal_repr(
+                    distribution.to_external_repr(
+                        distribution.low if hasattr(distribution, "low") else 0.0
+                    )
                 )
-            )
-        else:
-            internal = self._sample(name, distribution, frozen)
-
-        storage.set_trial_param(self._trial_id, name, internal, distribution)
+            else:
+                internal = self._sample(name, distribution, frozen)
+            storage.set_trial_param(self._trial_id, name, internal, distribution)
         self._cached = None
         return distribution.to_external_repr(internal)
 

@@ -25,6 +25,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import ops
+from ..core import telemetry
 
 __all__ = ["parzen_score_kernel", "parzen_score"]
 
@@ -117,6 +118,7 @@ def _parzen_padded(
         out_shape=jax.ShapeDtypeStruct((C_p, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_c, 1), jnp.float32) for _ in range(4)],
         interpret=interpret,
+        name="parzen_score",
     )(cands.reshape(C_p, 1), comps)
     return out[:, 0]
 
@@ -135,49 +137,51 @@ def parzen_score(
     All shape normalization (common component length, block-multiple padding)
     happens *outside* the jit boundary, so the compile cache keys on the
     padded shapes: pre-bucketed callers with unequal ``Kl``/``Kg`` (or raw
-    callers inside one bucket) share a single trace.
+    callers inside one bucket) share a single trace.  Telemetry times that
+    preparation (float32 conversion, padding, host-to-device copies) as
+    ``parzen.prepare`` and the kernel's dispatch as ``parzen.launch``.
     """
+    with telemetry.span("parzen.prepare"):
+        def prep(x):
+            return jnp.asarray(x, jnp.float32)
 
-    def prep(x):
-        return jnp.asarray(x, jnp.float32)
+        cands = prep(cands)
+        C = cands.shape[0]
+        K = max(l_mus.shape[0], g_mus.shape[0])
 
-    cands = prep(cands)
-    C = cands.shape[0]
-    K = max(l_mus.shape[0], g_mus.shape[0])
+        def pad_side(mus, sigmas, ln):
+            k = mus.shape[0]
+            if k < K:
+                mus = jnp.pad(prep(mus), (0, K - k))
+                sigmas = jnp.pad(prep(sigmas), (0, K - k), constant_values=1.0)
+                ln = jnp.pad(prep(ln), (0, K - k), constant_values=NEG_INF)
+                return mus, sigmas, ln
+            return prep(mus), prep(sigmas), prep(ln)
 
-    def pad_side(mus, sigmas, ln):
-        k = mus.shape[0]
-        if k < K:
-            mus = jnp.pad(prep(mus), (0, K - k))
-            sigmas = jnp.pad(prep(sigmas), (0, K - k), constant_values=1.0)
-            ln = jnp.pad(prep(ln), (0, K - k), constant_values=NEG_INF)
-            return mus, sigmas, ln
-        return prep(mus), prep(sigmas), prep(ln)
+        l_side = pad_side(l_mus, l_sigmas, l_log_norm)
+        g_side = pad_side(g_mus, g_sigmas, g_log_norm)
 
-    l_side = pad_side(l_mus, l_sigmas, l_log_norm)
-    g_side = pad_side(g_mus, g_sigmas, g_log_norm)
+        block_c = min(block_c, C)
+        block_k = min(block_k, K)
+        C_p = -(-C // block_c) * block_c
+        K_p = -(-K // block_k) * block_k
+        if C_p != C:
+            cands = jnp.pad(cands, (0, C_p - C))
+        if K_p != K:
+            pad = (0, K_p - K)
 
-    block_c = min(block_c, C)
-    block_k = min(block_k, K)
-    C_p = -(-C // block_c) * block_c
-    K_p = -(-K // block_k) * block_k
-    if C_p != C:
-        cands = jnp.pad(cands, (0, C_p - C))
-    if K_p != K:
-        pad = (0, K_p - K)
+            def pad_tail(side):
+                mus, sigmas, ln = side
+                return (
+                    jnp.pad(mus, pad),
+                    jnp.pad(sigmas, pad, constant_values=1.0),
+                    jnp.pad(ln, pad, constant_values=NEG_INF),
+                )
 
-        def pad_tail(side):
-            mus, sigmas, ln = side
-            return (
-                jnp.pad(mus, pad),
-                jnp.pad(sigmas, pad, constant_values=1.0),
-                jnp.pad(ln, pad, constant_values=NEG_INF),
-            )
-
-        l_side, g_side = pad_tail(l_side), pad_tail(g_side)
-
-    out = _parzen_padded(
-        cands, *l_side, *g_side,
-        block_c=block_c, block_k=block_k, interpret=interpret,
-    )
-    return out[:C]
+            l_side, g_side = pad_tail(l_side), pad_tail(g_side)
+    with telemetry.span("parzen.launch"):
+        out = _parzen_padded(
+            cands, *l_side, *g_side,
+            block_c=block_c, block_k=block_k, interpret=interpret,
+        )
+        return out[:C]

@@ -7,6 +7,7 @@ falls back to replication (e.g. kv_heads=4 on a 16-way "model" axis).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 from typing import Any, Sequence
 
@@ -197,8 +198,10 @@ def constrain(x, logical: Sequence[str | None]):
 
 def wrap_with_sharding_ctx(fn, mesh: Mesh, rules: ShardingRules):
     """Make ``fn`` trace (and thus jit-compile) inside the activation-sharding
-    context."""
+    context.  The wrapper keeps ``fn``'s name, which names the jitted
+    program (``jit_train_step``)."""
 
+    @functools.wraps(fn)
     def wrapped(*args, **kwargs):
         with activation_sharding(mesh, rules):
             return fn(*args, **kwargs)

@@ -9,6 +9,12 @@ the trainer's mesh (a trial on mesh slice k lives on slice k's devices),
 checkpoint/restart (auto-resume from the latest step), deterministic data
 skip on resume, eval hooks that feed the HPO pruner, and graceful preemption
 (SIGTERM -> final checkpoint).
+
+``Trainer.run`` records its phases as telemetry spans (on the profiler's
+host timeline when telemetry is enabled): ``train.init``, then per step
+``train.batch``, ``train.compile`` (the first step call) or
+``train.dispatch``, and at each eval ``train.loss_sync`` and
+``train.report``.  Run by the trial scheduler, they carry the trial's id.
 """
 
 from __future__ import annotations
@@ -24,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import telemetry
 from repro.models import (
     ModelConfig,
     abstract_params,
@@ -83,7 +90,7 @@ def make_train_step(cfg: ModelConfig, opt: Optimizer, microbatch: int = 0) -> Ca
         )(params)
         return loss, metrics, grads
 
-    def step(params, opt_state, step_no, batch):
+    def train_step(params, opt_state, step_no, batch):
         if microbatch and microbatch > 1:
             # grad accumulation: scan over microbatch slices of the batch dim
             def resh(x):
@@ -111,7 +118,7 @@ def make_train_step(cfg: ModelConfig, opt: Optimizer, microbatch: int = 0) -> Ca
         out_metrics = {"loss": loss, **metrics, **opt_metrics}
         return new_params, new_opt, out_metrics
 
-    return step
+    return train_step
 
 
 def make_sharded_init(cfg: ModelConfig, opt: Optimizer, mesh, rules: ShardingRules):
@@ -248,44 +255,51 @@ class Trainer:
     def run(self) -> dict:
         self._install_sigterm()
         cfg, tcfg = self.cfg, self.tcfg
-        key = jax.random.PRNGKey(tcfg.seed)
-        train_step = make_train_step(cfg, self.opt, tcfg.microbatch)
-        start_step = 0
-        state_sh = b_sh = None
-        if self.mesh is None:
-            params = init_model_params(cfg, key)
-            opt_state = self.opt.init(params)
-            step_fn = jax.jit(train_step, donate_argnums=(0, 1))
-        else:
-            mesh, rules = self.mesh, self.rules or TRAIN_RULES
-            init, p_sh, o_sh = make_sharded_init(cfg, self.opt, mesh, rules)
-            params, opt_state = init(key)
-            state_sh = (p_sh, o_sh)
-            step_fn = None  # jitted on the first batch, whose shapes it needs
-        if self.ckpt is not None:
-            restored = self.ckpt.restore_latest((params, opt_state), state_sh)
-            if restored is not None:
-                start_step, (params, opt_state) = restored
+        with telemetry.span("train.init"):
+            key = jax.random.PRNGKey(tcfg.seed)
+            train_step = make_train_step(cfg, self.opt, tcfg.microbatch)
+            start_step = 0
+            state_sh = b_sh = None
+            if self.mesh is None:
+                params = init_model_params(cfg, key)
+                opt_state = self.opt.init(params)
+                step_fn = jax.jit(train_step, donate_argnums=(0, 1))
+            else:
+                mesh, rules = self.mesh, self.rules or TRAIN_RULES
+                init, p_sh, o_sh = make_sharded_init(cfg, self.opt, mesh, rules)
+                params, opt_state = init(key)
+                state_sh = (p_sh, o_sh)
+                step_fn = None  # jitted on the first batch, whose shapes it needs
+            if self.ckpt is not None:
+                restored = self.ckpt.restore_latest((params, opt_state), state_sh)
+                if restored is not None:
+                    start_step, (params, opt_state) = restored
 
         self.data.skip_to(start_step)
         losses = []
         last = None
         for step in range(start_step, tcfg.total_steps):
-            batch = self.data.next_batch()
-            if self.mesh is not None:
-                if step_fn is None:
-                    b_sh = batch_shardings(batch, mesh, rules)
-                    step_fn = _jit_on_mesh(train_step, mesh, rules, state_sh, b_sh)
-                # host numpy straight onto this mesh's devices
-                batch = jax.device_put(batch, b_sh)
-            params, opt_state, metrics = step_fn(
-                params, opt_state, np.int32(step), batch
-            )
+            with telemetry.span("train.batch"):
+                batch = self.data.next_batch()
+                if self.mesh is not None:
+                    if step_fn is None:
+                        b_sh = batch_shardings(batch, mesh, rules)
+                        step_fn = _jit_on_mesh(train_step, mesh, rules, state_sh, b_sh)
+                    # host numpy straight onto this mesh's devices
+                    batch = jax.device_put(batch, b_sh)
+            # the first call traces, lowers and compiles the step
+            with telemetry.span("train.compile" if step == start_step else "train.dispatch"):
+                params, opt_state, metrics = step_fn(
+                    params, opt_state, np.int32(step), batch
+                )
             last = metrics
             if (step + 1) % tcfg.eval_every == 0 or step + 1 == tcfg.total_steps:
-                loss = float(metrics["loss"])
+                with telemetry.span("train.loss_sync"):
+                    loss = float(metrics["loss"])
                 losses.append(loss)
-                if self.report_fn is not None and self.report_fn(step + 1, loss):
+                with telemetry.span("train.report"):
+                    stop = self.report_fn is not None and self.report_fn(step + 1, loss)
+                if stop:
                     # pruned by the HPO layer: stop immediately, do not checkpoint
                     # (the paper's no-repechage design: pruned trials never resume)
                     return {"pruned": True, "last_loss": loss, "step": step + 1,

@@ -51,8 +51,6 @@ class TrialSliceScheduler:
     def _log(self, kind: str, slice_id: int, trial_number: int) -> None:
         with self._lock:
             self._events.append((kind, slice_id, trial_number))
-        if telemetry.enabled():  # start/done/pruned/failed per-slice throughput
-            telemetry.inc(f"scheduler.{kind}")
 
     @property
     def events(self) -> list:
@@ -108,9 +106,12 @@ class TrialSliceScheduler:
         def slice_worker(slice_id: int, mesh) -> None:
             while take():
                 trial = next_trial()
-                self._log("start", slice_id, trial.number)
+                number = trial.number
+                self._log("start", slice_id, number)
                 try:
-                    value = self.run_trial(trial, mesh)
+                    # the trial's id labels every span its run records
+                    with telemetry.span("scheduler.trial", trial=number):
+                        value = self.run_trial(trial, mesh)
                 except hpo.TrialPruned:
                     # record the highest-step reported value as the final
                     # value (matching Study._run_one's last_step choice); the
@@ -120,18 +121,18 @@ class TrialSliceScheduler:
                     last = trial.last_reported
                     final = last[1] if last is not None and last[1] == last[1] else None
                     self.study.tell(trial, final, state=TrialState.PRUNED)
-                    self._log("pruned", slice_id, trial.number)
+                    self._log("pruned", slice_id, number)
                     continue
                 except Exception as e:
                     # a failed trial frees its slice for the next one; the
                     # traceback goes to the log, the cause onto the trial
-                    _log.exception("trial %d failed on slice %d", trial.number, slice_id)
+                    _log.exception("trial %d failed on slice %d", number, slice_id)
                     trial.set_system_attr("fail:exception", repr(e))
                     self.study.tell(trial, state=TrialState.FAIL)
-                    self._log("failed", slice_id, trial.number)
+                    self._log("failed", slice_id, number)
                     continue
                 self.study.tell(trial, value)
-                self._log("done", slice_id, trial.number)
+                self._log("done", slice_id, number)
 
         threads = [
             threading.Thread(target=slice_worker, args=(i, m), daemon=True)

@@ -1,6 +1,7 @@
 """Telemetry backbone: registry semantics, spans, trial event trace, and the
 server metrics surface (ISSUE 6 acceptance)."""
 
+import os
 import threading
 import time
 
@@ -282,3 +283,126 @@ def test_disabled_span_overhead_tiny():
             pass
     per_call = (time.perf_counter_ns() - t0) / n
     assert per_call < 5_000  # ns; generous CI bound, typically ~250ns
+
+
+# -- spans on the profiler's timeline -----------------------------------------
+
+
+def test_disabled_span_with_ids_is_the_shared_noop():
+    assert telemetry.span("train.compile", trial=3) is telemetry._NOOP
+    assert telemetry.span("tpe.score") is telemetry._NOOP
+
+
+def test_importing_telemetry_imports_no_jax():
+    """The storage server records through this module without jax: neither
+    the import nor an enabled span may load it."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from repro.core import telemetry\n"
+        "telemetry.enable()\n"
+        "with telemetry.span('storage.report_and_prune', trial=1):\n"
+        "    pass\n"
+        "assert telemetry.snapshot()['histograms']['storage.report_and_prune']['count'] == 1\n"
+        "assert 'jax' not in sys.modules, 'jax was imported'\n"
+    )
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
+                         env={**os.environ, "PYTHONPATH": os.path.abspath(src)}, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+
+
+def _traced_spans(tmp_path, fn, prefixes: tuple) -> list:
+    """Run ``fn`` with telemetry enabled under a CPU profiler trace; return
+    the host events whose names start with ``prefixes`` as ``(name, start,
+    end, line, stats)``, in start order."""
+    import glob
+
+    jax = pytest.importorskip("jax")
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    telemetry.enable()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+        telemetry.disable()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"), recursive=True)
+    out = []
+    for p, plane in enumerate(ProfileData.from_file(path).planes):
+        for k, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith(prefixes):
+                    out.append((e.name, e.start_ns, e.end_ns, (p, k), dict(e.stats)))
+    return sorted(out, key=lambda s: (s[1], -s[2]))
+
+
+def test_enabled_span_is_on_the_profiler_trace_nested_with_its_trial(tmp_path):
+    def work():
+        with telemetry.span("scheduler.trial", trial=3):
+            with telemetry.span("train.compile"):
+                time.sleep(0.002)
+        with telemetry.span("train.report"):  # outside any trial
+            pass
+
+    spans = _traced_spans(tmp_path, work, ("scheduler.", "train."))
+    assert [s[0] for s in spans] == ["scheduler.trial", "train.compile", "train.report"]
+    outer, inner, after = spans
+    assert inner[3] == outer[3]  # one thread's line
+    assert outer[1] <= inner[1] and inner[2] <= outer[2]
+    assert outer[4]["trial"] == 3 and inner[4]["trial"] == 3  # inherited
+    assert "trial" not in after[4]
+    # the histograms are recorded as before
+    assert telemetry.snapshot()["histograms"]["train.compile"]["count"] == 1
+
+
+def test_trainer_records_its_phases_in_order(tmp_path):
+    from repro import configs
+    from repro.launch.mesh import make_auto_mesh
+    from repro.train import SyntheticLM, TrainConfig, Trainer
+
+    jax = pytest.importorskip("jax")
+    cfg = configs.get_smoke_config("smollm-135m")
+    tcfg = TrainConfig(total_steps=2, eval_every=2, warmup_steps=1)
+    mesh = make_auto_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    reports = []
+
+    def run():
+        trainer = Trainer(cfg, tcfg, SyntheticLM(cfg, 2, 32), mesh=mesh,
+                          report_fn=lambda step, loss: reports.append(step) or False)
+        with telemetry.span("scheduler.trial", trial=7):
+            trainer.run()
+
+    spans = _traced_spans(tmp_path, run, ("train.",))
+    assert [s[0] for s in spans] == [
+        "train.init", "train.batch", "train.compile", "train.batch", "train.dispatch",
+        "train.loss_sync", "train.report",
+    ]
+    assert all(s[4].get("trial") == 7 for s in spans)
+    assert reports == [2]
+
+
+def test_device_tpe_ask_records_the_scoring_round_trip(tmp_path):
+    """On the Pallas engine (interpret mode on the CPU) each parameter's
+    score is put on the device, launched and fetched, inside ``tpe.score``."""
+    study = hpo.create_study(sampler=hpo.TPESampler(seed=0, n_startup_trials=5, engine="pallas"))
+    study.optimize(lambda t: t.suggest_float("x", -1, 1) ** 2, n_trials=6)
+
+    def ask():
+        trial = study.ask()
+        trial.suggest_float("x", -1, 1)
+
+    spans = _traced_spans(tmp_path, ask, ("tpe.", "parzen.", "trial."))
+    names = [s[0] for s in spans]
+    assert names == ["trial.suggest", "tpe.fit", "tpe.estimate", "tpe.score",
+                     "parzen.prepare", "parzen.launch", "tpe.score.fetch"]
+    score = spans[names.index("tpe.score")]
+    for name in ("parzen.prepare", "parzen.launch", "tpe.score.fetch"):
+        s = spans[names.index(name)]
+        assert score[1] <= s[1] and s[2] <= score[2]
+    assert all(s[4].get("trial") == 6 for s in spans)

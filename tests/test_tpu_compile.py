@@ -72,6 +72,19 @@ def test_parzen_kernel_compiles_for_v5e(one_chip, no_compile_cache, C, K):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_parzen_kernel_is_named_for_the_trace(one_chip, no_compile_cache):
+    """The kernel's custom call carries the Pallas call's name, which the
+    benchmark's ``parzen_kernel_us`` finds in the trace's operations."""
+    from repro.kernels.parzen import _parzen_padded
+
+    compiled = _parzen_padded.lower(
+        _spec((24,), one_chip), *[_spec((1024,), one_chip)] * 6,
+        block_c=24, block_k=1024, interpret=False,
+    ).compile()
+    calls = [ln for ln in compiled.as_text().splitlines() if "tpu_custom_call" in ln]
+    assert calls and all(ln.strip().startswith("%parzen_score") for ln in calls)
+
+
 @pytest.mark.parametrize("n,m", [(64, 5), (512, 8)])
 def test_mc_hv_kernel_compiles_for_v5e(one_chip, no_compile_cache, n, m):
     from repro.kernels.hypervolume import _mc_hv_padded, default_block_s
