@@ -6,7 +6,6 @@ from .optimizer import (
     Optimizer,
     adafactor,
     adamw,
-    constant_schedule,
     global_norm,
     make_optimizer,
     sgd,
@@ -17,7 +16,7 @@ from .train_loop import TrainConfig, Trainer, make_sharded_init, make_train_step
 __all__ = [
     "TrainConfig", "Trainer", "make_train_step", "make_sharded_init",
     "Optimizer", "adamw", "adafactor", "sgd", "make_optimizer",
-    "warmup_cosine", "constant_schedule", "global_norm",
+    "warmup_cosine", "global_norm",
     "CheckpointManager", "save_pytree", "restore_pytree",
     "SyntheticLM", "MemmapTokens", "make_data",
 ]

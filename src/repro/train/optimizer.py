@@ -1,9 +1,13 @@
 """Optimizers in pure JAX: AdamW, Adafactor (factored second moments — the
-235B-config choice), SGD+momentum; global-norm clipping; warmup+cosine
-schedules.
+235B-config choice), SGD+momentum; global-norm clipping; a warmup+cosine
+schedule.
 
 Optimizer state is a pytree parallel to params, so GSPMD shards it exactly
 like the parameters (ZeRO-style for free when params are FSDP-sharded).
+Beside it, under ``"hyper"``, the state holds the optimizer's settings
+(learning rate, schedule, decay, betas, clipping) as float32 scalars that the
+update reads, as optax's ``inject_hyperparams`` does: a compiled step takes
+them as operands, so trials that differ only in their settings share it.
 """
 
 from __future__ import annotations
@@ -14,6 +18,7 @@ from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = [
     "Optimizer",
@@ -22,7 +27,6 @@ __all__ = [
     "sgd",
     "make_optimizer",
     "warmup_cosine",
-    "constant_schedule",
     "global_norm",
     "clip_by_global_norm",
 ]
@@ -39,31 +43,58 @@ def clip_by_global_norm(tree, max_norm: float):
     return jax.tree.map(lambda g: (g.astype(jnp.float32) * scale).astype(g.dtype), tree), norm
 
 
-def warmup_cosine(peak_lr: float, warmup: int, total: int, floor: float = 0.1) -> Callable:
+def warmup_cosine(peak_lr, warmup, total, floor: float = 0.1) -> Callable:
+    """Linear warm-up to ``peak_lr`` over ``warmup`` steps, then a cosine to
+    ``floor * peak_lr`` at ``total``.  The settings may be numbers or float32
+    scalars (operands of a compiled step)."""
+
     def schedule(step):
         step = jnp.asarray(step, jnp.float32)
         # (step+1)/warmup so the very first step trains (lr > 0 at step 0)
-        warm = peak_lr * jnp.minimum(1.0, (step + 1.0) / max(warmup, 1))
-        frac = jnp.clip((step - warmup) / max(total - warmup, 1), 0.0, 1.0)
+        warm = peak_lr * jnp.minimum(1.0, (step + 1.0) / jnp.maximum(warmup, 1))
+        frac = jnp.clip((step - warmup) / jnp.maximum(total - warmup, 1), 0.0, 1.0)
         cos = peak_lr * (floor + (1 - floor) * 0.5 * (1 + jnp.cos(jnp.pi * frac)))
         return jnp.where(step < warmup, warm, cos)
 
     return schedule
 
 
-def constant_schedule(lr: float) -> Callable:
-    return lambda step: jnp.asarray(lr, jnp.float32)
-
-
 @dataclasses.dataclass(frozen=True)
 class Optimizer:
-    init: Callable[[Any], Any]
+    """An optimizer's programs, which depend on ``key`` alone, and its
+    settings ``hyper``.
+
+    ``init(params)`` returns the state: the moments, and the settings as
+    float32 scalars under ``"hyper"``; ``update(grads, state, params, step)``
+    reads them from there and returns them unchanged.  Optimizers with equal
+    ``key`` (the kind and the options that shape the program) share every
+    compiled program."""
+
+    moments: Callable[[Any], dict]
     update: Callable[[Any, Any, Any, jax.Array], tuple]  # (grads, state, params, step)
-    name: str = "opt"
+    hyper: dict
+    key: tuple
+
+    def init(self, params, hyper: dict | None = None) -> dict:
+        """The state for ``params``, holding ``hyper`` (by default this
+        optimizer's own settings)."""
+        hyper = self.hyper if hyper is None else hyper
+        return {**self.moments(params),
+                "hyper": {k: jnp.asarray(v, jnp.float32) for k, v in hyper.items()}}
+
+
+def _float32(**settings) -> dict:
+    return {k: np.float32(v) for k, v in settings.items()}
+
+
+def _schedule(h: dict) -> Callable:
+    return warmup_cosine(h["lr"], h["warmup_steps"], h["total_steps"])
 
 
 def adamw(
-    schedule: Callable,
+    lr: float,
+    warmup_steps: int,
+    total_steps: int,
     b1: float = 0.9,
     b2: float = 0.95,
     eps: float = 1e-8,
@@ -71,7 +102,7 @@ def adamw(
     clip_norm: float = 1.0,
     state_dtype=jnp.float32,
 ) -> Optimizer:
-    def init(params):
+    def moments(params):
         zeros = lambda p: jnp.zeros(p.shape, state_dtype)
         return {
             "m": jax.tree.map(zeros, params),
@@ -79,11 +110,13 @@ def adamw(
         }
 
     def update(grads, state, params, step):
-        grads, gnorm = clip_by_global_norm(grads, clip_norm)
+        h = state["hyper"]
+        b1, b2, weight_decay = h["b1"], h["b2"], h["weight_decay"]
+        grads, gnorm = clip_by_global_norm(grads, h["clip_norm"])
         t = step.astype(jnp.float32) + 1.0
         c1 = 1.0 - b1**t
         c2 = 1.0 - b2**t
-        lr = schedule(step)
+        lr = _schedule(h)(step)
 
         def upd(g, m, v, p):
             g32 = g.astype(jnp.float32)
@@ -99,13 +132,17 @@ def adamw(
         p_new = jax.tree.map(lambda o: o[0], out, is_leaf=lambda x: isinstance(x, tuple))
         m_new = jax.tree.map(lambda o: o[1], out, is_leaf=lambda x: isinstance(x, tuple))
         v_new = jax.tree.map(lambda o: o[2], out, is_leaf=lambda x: isinstance(x, tuple))
-        return p_new, {"m": m_new, "v": v_new}, {"grad_norm": gnorm, "lr": lr}
+        return p_new, {"m": m_new, "v": v_new, "hyper": h}, {"grad_norm": gnorm, "lr": lr}
 
-    return Optimizer(init, update, "adamw")
+    hyper = _float32(lr=lr, warmup_steps=warmup_steps, total_steps=total_steps, b1=b1, b2=b2,
+                     weight_decay=weight_decay, clip_norm=clip_norm)
+    return Optimizer(moments, update, hyper, ("adamw", eps, jnp.dtype(state_dtype).name))
 
 
 def adafactor(
-    schedule: Callable,
+    lr: float,
+    warmup_steps: int,
+    total_steps: int,
     decay: float = 0.99,
     eps: float = 1e-30,
     clip_threshold: float = 1.0,
@@ -120,7 +157,7 @@ def adafactor(
     def factored(p) -> bool:
         return p.ndim >= 2 and p.shape[-1] > 1 and p.shape[-2] > 1
 
-    def init(params):
+    def moments(params):
         def one(p):
             if factored(p):
                 return {
@@ -132,8 +169,10 @@ def adafactor(
         return {"v": jax.tree.map(one, params)}
 
     def update(grads, state, params, step):
-        grads, gnorm = clip_by_global_norm(grads, clip_norm)
-        lr = schedule(step)
+        h = state["hyper"]
+        weight_decay = h["weight_decay"]
+        grads, gnorm = clip_by_global_norm(grads, h["clip_norm"])
+        lr = _schedule(h)(step)
         t = step.astype(jnp.float32) + 1.0
         beta2t = 1.0 - jnp.power(t, -0.8)  # Adafactor's decay schedule
 
@@ -165,18 +204,27 @@ def adafactor(
         is_pair = lambda x: isinstance(x, tuple)
         p_new = jax.tree.map(lambda o: o[0], out, is_leaf=is_pair)
         v_new = jax.tree.map(lambda o: o[1], out, is_leaf=is_pair)
-        return p_new, {"v": v_new}, {"grad_norm": gnorm, "lr": lr}
+        return p_new, {"v": v_new, "hyper": h}, {"grad_norm": gnorm, "lr": lr}
 
-    return Optimizer(init, update, "adafactor")
+    hyper = _float32(lr=lr, warmup_steps=warmup_steps, total_steps=total_steps,
+                     weight_decay=weight_decay, clip_norm=clip_norm)
+    return Optimizer(moments, update, hyper, ("adafactor", decay, eps, clip_threshold))
 
 
-def sgd(schedule: Callable, momentum: float = 0.9, clip_norm: float = 1.0) -> Optimizer:
-    def init(params):
+def sgd(
+    lr: float,
+    warmup_steps: int,
+    total_steps: int,
+    momentum: float = 0.9,
+    clip_norm: float = 1.0,
+) -> Optimizer:
+    def moments(params):
         return {"mu": jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)}
 
     def update(grads, state, params, step):
-        grads, gnorm = clip_by_global_norm(grads, clip_norm)
-        lr = schedule(step)
+        h = state["hyper"]
+        grads, gnorm = clip_by_global_norm(grads, h["clip_norm"])
+        lr = _schedule(h)(step)
 
         def upd(g, mu, p):
             mu_new = momentum * mu + g.astype(jnp.float32)
@@ -186,18 +234,19 @@ def sgd(schedule: Callable, momentum: float = 0.9, clip_norm: float = 1.0) -> Op
         is_pair = lambda x: isinstance(x, tuple)
         return (
             jax.tree.map(lambda o: o[0], out, is_leaf=is_pair),
-            {"mu": jax.tree.map(lambda o: o[1], out, is_leaf=is_pair)},
+            {"mu": jax.tree.map(lambda o: o[1], out, is_leaf=is_pair), "hyper": h},
             {"grad_norm": gnorm, "lr": lr},
         )
 
-    return Optimizer(init, update, "sgd")
+    hyper = _float32(lr=lr, warmup_steps=warmup_steps, total_steps=total_steps, clip_norm=clip_norm)
+    return Optimizer(moments, update, hyper, ("sgd", momentum))
 
 
-def make_optimizer(name: str, schedule: Callable, **kw) -> Optimizer:
+def make_optimizer(name: str, **kw) -> Optimizer:
     if name == "adamw":
-        return adamw(schedule, **kw)
+        return adamw(**kw)
     if name == "adafactor":
-        return adafactor(schedule, **kw)
+        return adafactor(**kw)
     if name == "sgd":
-        return sgd(schedule, **kw)
+        return sgd(**kw)
     raise ValueError(f"unknown optimizer {name!r}")

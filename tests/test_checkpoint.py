@@ -1,5 +1,6 @@
 """Checkpoint save/restore incl. resharding restore and trainer auto-resume."""
 
+import json
 import os
 
 import jax
@@ -17,6 +18,7 @@ from repro.train import (
     restore_pytree,
     save_pytree,
 )
+from repro.train.train_loop import make_optimizer_for
 
 
 def test_roundtrip_pytree(tmp_path):
@@ -70,6 +72,30 @@ def test_trainer_resume_continues_step_count(tmp_path):
     )
     res = t2.run()
     assert res["step"] == 10
+
+
+@pytest.mark.parametrize("on_mesh", [False, True])
+def test_trainer_resumes_from_a_checkpoint_without_settings(tmp_path, on_mesh):
+    """A checkpoint holds the params and the optimizer's moments, not its
+    settings (as every checkpoint written before the settings joined the
+    optimizer state): a trainer resumes from one under its own settings,
+    and what it writes holds none either."""
+    from repro.launch.mesh import make_auto_mesh
+
+    cfg = configs.get_smoke_config("smollm-135m")
+    tcfg = TrainConfig(total_steps=4, checkpoint_every=2, eval_every=2)
+    params = init_model_params(cfg, jax.random.PRNGKey(0))
+    moments = {k: v for k, v in make_optimizer_for(cfg, tcfg).init(params).items() if k != "hyper"}
+    mgr = CheckpointManager(str(tmp_path))
+    mgr.save(2, (params, moments), blocking=True)
+    mesh = make_auto_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1]) if on_mesh else None
+    res = Trainer(cfg, tcfg, SyntheticLM(cfg, batch=2, seq=32, seed=0), mesh=mesh,
+                  workdir=str(tmp_path)).run()
+    assert res["step"] == 4 and len(res["losses"]) == 1 and np.isfinite(res["losses"][0])
+    assert mgr.all_steps()[-1] == 4
+    with open(mgr._path(4) + ".json") as f:
+        keys = json.load(f)["keys"]
+    assert keys and not any("hyper" in k for k in keys)
 
 
 def test_restore_under_different_sharding_subprocess(tmp_path):
