@@ -297,7 +297,9 @@ def _build_parzen_score_op():
 
     def parzen_score_op(cands, l_mus, l_sigmas, l_log_norm, g_mus, g_sigmas, g_log_norm):
         """Fused Parzen ``log l - log g`` over candidates (Pallas; interpret
-        mode off-TPU).  Component arrays should arrive pow2-padded."""
+        mode off-TPU).  Component arrays should arrive pow2-padded.  The 7
+        host arrays are packed in numpy into the kernel's two operands and
+        put on the device at once, so a call runs one device program."""
         return parzen_score(
             cands, l_mus, l_sigmas, l_log_norm, g_mus, g_sigmas, g_log_norm,
             interpret=should_interpret(),

@@ -10,9 +10,13 @@ TPE device engine's score table (``SCORE_TABLE_SIZE`` grid points per call)
 and any ask wave with many pending trials.
 
 Component arrays arrive padded to power-of-two buckets (``ops.pad_pow2_vec``
-with ``log_norm = -inf``) so XLA retraces O(log n_components) times; the
-wrapper additionally pads both mixtures to one common length so a single
-grid serves the ``l`` and ``g`` sides.
+with ``log_norm = -inf``) so XLA retraces O(log n_components) times.  The
+wrapper packs every input on the host in numpy (float32 cast, both mixtures
+padded to one common block-multiple length in a single ``[8, K_p]`` block so
+one grid serves the ``l`` and ``g`` sides, candidates as a ``[C_p, 1]``
+column), puts the two arrays on the device with one ``jax.device_put``, and
+runs the kernel and its ``[:C]`` slice as one jitted program: a scoring call
+is one device program.
 """
 
 from __future__ import annotations
@@ -21,6 +25,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -81,30 +86,54 @@ def parzen_score_kernel(
 #: round the block up to one (8, 128) f32 tile height
 _COMP_ROWS = 8
 
+#: what the padding past a mixture's own components holds, per row of a side:
+#: mu 0, sigma 1, log_norm NEG_INF (inert in the logsumexp)
+_SIDE_FILL = (0.0, 1.0, NEG_INF)
+
+
+def _pack(cands, sides, *, block_c: int, block_k: int):
+    """The kernel's operands, built on the host in numpy: candidates as a
+    ``[C_p, 1]`` float32 column (0 past ``C``) and both mixtures as one
+    ``[8, K_p]`` float32 block in ``_COMP_ROWS`` order, each side padded to
+    the common block-multiple length with ``_SIDE_FILL`` and the two filler
+    rows 1.0.  Returns them with the clamped block sizes; float64 inputs
+    are rounded to nearest-even on assignment, as XLA's convert does."""
+    C = len(cands)
+    K = max(len(side[0]) for side in sides)
+    block_c, block_k = min(block_c, C), min(block_k, K)
+    C_p = -(-C // block_c) * block_c
+    K_p = -(-K // block_k) * block_k
+    col = np.zeros((C_p, 1), np.float32)
+    col[:C, 0] = np.asarray(cands)
+    comps = np.empty((_COMP_ROWS, K_p), np.float32)
+    for base, side in zip((0, 3), sides):
+        for row, (x, fill) in enumerate(zip(side, _SIDE_FILL), start=base):
+            k = len(x)
+            comps[row, :k] = np.asarray(x)
+            comps[row, k:] = fill
+    comps[6:] = 1.0
+    return col, comps, block_c, block_k
+
 
 @functools.partial(
-    jax.jit, static_argnames=("block_c", "block_k", "interpret")
+    jax.jit, static_argnames=("n_cands", "block_c", "block_k", "interpret")
 )
 def _parzen_padded(
-    cands: jax.Array,  # [C_p] block-multiple-padded
-    l_mus, l_sigmas, l_log_norm,  # [K_p] common padded length
-    g_mus, g_sigmas, g_log_norm,  # [K_p]
+    cands: jax.Array,  # [C_p, 1] block-multiple-padded
+    comps: jax.Array,  # [8, K_p] rows in _COMP_ROWS order
     *,
+    n_cands: int,
     block_c: int,
     block_k: int,
     interpret: bool,
 ) -> jax.Array:
     ops.bump_trace("pallas.parzen")  # traced body: runs once per trace
-    C_p, K_p = cands.shape[0], l_mus.shape[0]
+    C_p, K_p = cands.shape[0], comps.shape[1]
     nc, nk = C_p // block_c, K_p // block_k
 
     # 2-D layouts only: 1-D blocks get a tiling from XLA that Mosaic refuses
     # once a block is smaller than the array.  Components ride the lane axis
     # (one packed DMA per step), candidates the sublane axis.
-    filler = jnp.ones_like(l_mus)
-    comps = jnp.stack(
-        [l_mus, l_sigmas, l_log_norm, g_mus, g_sigmas, g_log_norm, filler, filler]
-    )
     kernel = functools.partial(parzen_score_kernel, n_comp_blocks=nk)
     cand_spec = pl.BlockSpec((block_c, 1), lambda ic, ik: (ic, 0))
     out = pl.pallas_call(
@@ -119,14 +148,14 @@ def _parzen_padded(
         scratch_shapes=[pltpu.VMEM((block_c, 1), jnp.float32) for _ in range(4)],
         interpret=interpret,
         name="parzen_score",
-    )(cands.reshape(C_p, 1), comps)
-    return out[:, 0]
+    )(cands, comps)
+    return out[:n_cands, 0]
 
 
 def parzen_score(
-    cands: jax.Array,  # [C]
-    l_mus: jax.Array, l_sigmas: jax.Array, l_log_norm: jax.Array,  # [Kl]
-    g_mus: jax.Array, g_sigmas: jax.Array, g_log_norm: jax.Array,  # [Kg]
+    cands,  # [C]
+    l_mus, l_sigmas, l_log_norm,  # [Kl]
+    g_mus, g_sigmas, g_log_norm,  # [Kg]
     *,
     block_c: int = 256,
     block_k: int = 1024,
@@ -134,54 +163,24 @@ def parzen_score(
 ) -> jax.Array:
     """``log l(cands) - log g(cands)`` as a [C] f32 array.
 
-    All shape normalization (common component length, block-multiple padding)
-    happens *outside* the jit boundary, so the compile cache keys on the
-    padded shapes: pre-bucketed callers with unequal ``Kl``/``Kg`` (or raw
-    callers inside one bucket) share a single trace.  Telemetry times that
-    preparation (float32 conversion, padding, host-to-device copies) as
-    ``parzen.prepare`` and the kernel's dispatch as ``parzen.launch``.
+    Inputs are numpy arrays (or jax arrays, copied back to the host).  One
+    call runs one device program: the host packs the candidates and both
+    mixtures in numpy (``_pack``: float32 cast, common component length,
+    block-multiple padding) and puts the two operands on the device with a
+    single ``jax.device_put``, timed as ``parzen.prepare``; the jitted
+    kernel and its ``[:C]`` slice are dispatched as ``parzen.launch``.  The
+    compile cache keys on the padded shapes and ``C``, so pre-bucketed
+    callers with unequal ``Kl``/``Kg`` share a single trace.
     """
     with telemetry.span("parzen.prepare"):
-        def prep(x):
-            return jnp.asarray(x, jnp.float32)
-
-        cands = prep(cands)
-        C = cands.shape[0]
-        K = max(l_mus.shape[0], g_mus.shape[0])
-
-        def pad_side(mus, sigmas, ln):
-            k = mus.shape[0]
-            if k < K:
-                mus = jnp.pad(prep(mus), (0, K - k))
-                sigmas = jnp.pad(prep(sigmas), (0, K - k), constant_values=1.0)
-                ln = jnp.pad(prep(ln), (0, K - k), constant_values=NEG_INF)
-                return mus, sigmas, ln
-            return prep(mus), prep(sigmas), prep(ln)
-
-        l_side = pad_side(l_mus, l_sigmas, l_log_norm)
-        g_side = pad_side(g_mus, g_sigmas, g_log_norm)
-
-        block_c = min(block_c, C)
-        block_k = min(block_k, K)
-        C_p = -(-C // block_c) * block_c
-        K_p = -(-K // block_k) * block_k
-        if C_p != C:
-            cands = jnp.pad(cands, (0, C_p - C))
-        if K_p != K:
-            pad = (0, K_p - K)
-
-            def pad_tail(side):
-                mus, sigmas, ln = side
-                return (
-                    jnp.pad(mus, pad),
-                    jnp.pad(sigmas, pad, constant_values=1.0),
-                    jnp.pad(ln, pad, constant_values=NEG_INF),
-                )
-
-            l_side, g_side = pad_tail(l_side), pad_tail(g_side)
+        col, comps, block_c, block_k = _pack(
+            cands,
+            ((l_mus, l_sigmas, l_log_norm), (g_mus, g_sigmas, g_log_norm)),
+            block_c=block_c, block_k=block_k,
+        )
+        col, comps = jax.device_put((col, comps))
     with telemetry.span("parzen.launch"):
-        out = _parzen_padded(
-            cands, *l_side, *g_side,
+        return _parzen_padded(
+            col, comps, n_cands=len(cands),
             block_c=block_c, block_k=block_k, interpret=interpret,
         )
-        return out[:C]

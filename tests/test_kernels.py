@@ -258,6 +258,124 @@ class TestParzenScoreKernel:
         np.testing.assert_allclose(np.asarray(out), np.asarray(expect), atol=2e-4, rtol=2e-4)
 
 
+class TestParzenPacking:
+    """The wrapper packs the kernel's inputs on the host in numpy and runs
+    one jitted program per call."""
+
+    def _sides(self, rng, Kl, Kg, dtype=np.float64):
+        def side(k):
+            ln = np.log(np.full(k, 1.0 / k)) - np.log(rng.uniform(0.05, 1.0, k))
+            ln[-1] = -np.inf  # pow2 padding from ``ops.pad_pow2_vec``
+            return (rng.uniform(-3, 3, k).astype(dtype),
+                    rng.uniform(0.05, 1.0, k).astype(dtype), ln.astype(dtype))
+
+        return side(Kl), side(Kg)
+
+    @pytest.mark.parametrize(
+        "C,Kl,Kg,bc,bk,C_p,K_p",
+        [
+            (100, 16, 40, 32, 16, 128, 48),  # candidates and components padded
+            (24, 32, 8192, 256, 1024, 24, 8192),  # the live ask's shapes
+            (300, 50, 7, 256, 1024, 512, 50),  # blocks clamp to C and K
+        ],
+    )
+    def test_layout(self, C, Kl, Kg, bc, bk, C_p, K_p):
+        from repro.kernels.parzen import NEG_INF, _pack
+
+        rng = np.random.RandomState(C + Kl + Kg)
+        cands = rng.uniform(-4, 4, C)
+        sides = self._sides(rng, Kl, Kg)
+        col, comps, block_c, block_k = _pack(cands, sides, block_c=bc, block_k=bk)
+        assert (block_c, block_k) == (min(bc, C), min(bk, max(Kl, Kg)))
+        assert isinstance(col, np.ndarray) and isinstance(comps, np.ndarray)
+        assert col.dtype == comps.dtype == np.float32
+        assert col.shape == (C_p, 1) and comps.shape == (8, K_p)
+        np.testing.assert_array_equal(col[:C, 0], cands.astype(np.float32))
+        assert (col[C:] == 0.0).all()
+        for base, side in zip((0, 3), sides):
+            k = len(side[0])
+            for row, (x, fill) in enumerate(zip(side, (0.0, 1.0, NEG_INF)), start=base):
+                np.testing.assert_array_equal(comps[row, :k], x.astype(np.float32))
+                assert (comps[row, k:] == np.float32(fill)).all()
+            assert comps[base + 2, k - 1] == -np.inf  # upstream padding kept
+        assert (comps[6:] == 1.0).all()
+
+    def test_float64_inputs_score_bit_identical_to_float32(self):
+        from repro.kernels.parzen import parzen_score
+
+        rng = np.random.RandomState(7)
+        cands = rng.uniform(-4, 4, 50)
+        l, g = self._sides(rng, 12, 30)
+        f32 = lambda xs: [x.astype(np.float32) for x in xs]
+        kw = dict(block_c=16, block_k=8, interpret=True)
+        out64 = np.asarray(parzen_score(cands, *l, *g, **kw))
+        out32 = np.asarray(parzen_score(*f32([cands]), *f32(l), *f32(g), **kw))
+        assert out64.shape == (50,) and out64.dtype == np.float32
+        assert np.isfinite(out64).all()
+        np.testing.assert_array_equal(out64, out32)
+
+    def test_one_trace_per_bucket_not_per_call(self):
+        from repro.kernels.ops import trace_count
+        from repro.kernels.parzen import parzen_score
+
+        rng = np.random.RandomState(3)
+        cands = rng.uniform(-4, 4, 37)
+        kw = dict(block_c=8, block_k=8, interpret=True)
+        before = trace_count("pallas.parzen")
+        # K_p 72: raw lengths 65..72 on either side share the bucket
+        for Kl, Kg in ((72, 60), (65, 72), (72, 72)):
+            l, g = self._sides(rng, Kl, Kg)
+            parzen_score(cands, *l, *g, **kw)
+        assert trace_count("pallas.parzen") - before == 1
+        for Kl, Kg in ((136, 9), (129, 136)):  # K_p 136: a new bucket
+            l, g = self._sides(rng, Kl, Kg)
+            parzen_score(cands, *l, *g, **kw)
+        assert trace_count("pallas.parzen") - before == 2
+        l, g = self._sides(rng, 70, 70)
+        parzen_score(cands, *l, *g, **kw)  # back to the first bucket
+        assert trace_count("pallas.parzen") - before == 2
+
+    def test_prepare_runs_no_jax_computation(self, monkeypatch):
+        """After the bucket's first call, a call touches ``jnp`` nowhere,
+        makes one ``device_put`` of two float32 numpy arrays, and dispatches
+        the jitted program once."""
+        import types
+
+        from repro.kernels import parzen
+
+        rng = np.random.RandomState(11)
+        cands = rng.uniform(-4, 4, 24)
+        l, g = self._sides(rng, 16, 20)
+        kw = dict(block_c=256, block_k=1024, interpret=True)
+        warm = np.asarray(parzen.parzen_score(cands, *l, *g, **kw))
+
+        class NoJnp:
+            def __getattr__(self, name):
+                raise AssertionError(f"jnp.{name} used by a scoring call")
+
+        puts, launches = [], []
+
+        def device_put(x, *a, **k):
+            puts.append(x)
+            return jax.device_put(x, *a, **k)
+
+        def launch(*a, **k):
+            launches.append(a)
+            return padded(*a, **k)
+
+        padded = parzen._parzen_padded
+        monkeypatch.setattr(parzen, "jnp", NoJnp())
+        monkeypatch.setattr(parzen, "jax", types.SimpleNamespace(device_put=device_put))
+        monkeypatch.setattr(parzen, "_parzen_padded", launch)
+        out = np.asarray(parzen.parzen_score(cands, *l, *g, **kw))
+        np.testing.assert_array_equal(out, warm)
+        assert len(puts) == 1 and len(launches) == 1
+        assert [(x.dtype, x.shape) for x in puts[0]] == [
+            (np.float32, (24, 1)), (np.float32, (8, 20))
+        ]
+        assert all(isinstance(x, np.ndarray) for x in puts[0])
+
+
 class TestMCHypervolumeKernel:
     @pytest.mark.parametrize(
         "n,m,s,bs",

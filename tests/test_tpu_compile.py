@@ -61,13 +61,9 @@ def test_parzen_kernel_compiles_for_v5e(one_chip, no_compile_cache, C, K):
     from repro.kernels.parzen import _parzen_padded
 
     block_c, block_k = min(256, C), min(1024, K)
-    fn = jax.jit(
-        lambda c, *comps: _parzen_padded(
-            c, *comps, block_c=block_c, block_k=block_k, interpret=False
-        )
-    )
-    compiled = fn.lower(
-        _spec((C,), one_chip), *[_spec((K,), one_chip)] * 6
+    compiled = _parzen_padded.lower(
+        _spec((C, 1), one_chip), _spec((8, K), one_chip),
+        n_cands=C, block_c=block_c, block_k=block_k, interpret=False,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -78,8 +74,8 @@ def test_parzen_kernel_is_named_for_the_trace(one_chip, no_compile_cache):
     from repro.kernels.parzen import _parzen_padded
 
     compiled = _parzen_padded.lower(
-        _spec((24,), one_chip), *[_spec((1024,), one_chip)] * 6,
-        block_c=24, block_k=1024, interpret=False,
+        _spec((24, 1), one_chip), _spec((8, 1024), one_chip),
+        n_cands=24, block_c=24, block_k=1024, interpret=False,
     ).compile()
     calls = [ln for ln in compiled.as_text().splitlines() if "tpu_custom_call" in ln]
     assert calls and all(ln.strip().startswith("%parzen_score") for ln in calls)
